@@ -1,5 +1,5 @@
 """qwen3-1.7b [dense]: 28L d_model=2048 16H (GQA kv=8) d_ff=6144
-vocab=151936 — qk_norm, GQA. [hf:Qwen/Qwen3-8B; hf]"""
+vocab=151936 — qk_norm, GQA, tied embeddings. [hf:Qwen/Qwen3-1.7B config.json]"""
 from repro.configs.shapes import ArchSpec, lm_shapes, FULL_ATTN_SKIP
 from repro.core.dora import AdapterConfig
 from repro.core.rram import RramConfig

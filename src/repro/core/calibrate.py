@@ -18,6 +18,7 @@ The CNN reproduction (``core/resnet.py``) uses the literal per-layer loop
 from __future__ import annotations
 
 import dataclasses
+import functools
 import zlib
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -90,6 +91,7 @@ def program_model(
     return jax.tree_util.tree_map_with_path(leaf, base)
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "mode"))
 def program_leaf(
     w: jax.Array, cfg: RramConfig, key: jax.Array, *, mode: str = "codes"
 ):
@@ -99,6 +101,10 @@ def program_leaf(
     fleet subsystem can ``jax.vmap`` it over per-chip keys — N chips'
     programming events land as ONE stacked draw, bitwise identical per
     chip to N sequential ``program_model`` calls with the same keys.
+
+    Jitted: run op by op, a stacked (layers, K, N) leaf would hold half a
+    dozen f32 temporaries of its full size at once (several GB at
+    published widths); compiled, the chain fuses into its outputs.
     """
     if w.ndim == 2:
         if mode == "codes":
@@ -155,6 +161,8 @@ def drift_model(
     """
     if (hours is None) == (sigma is None):
         raise ValueError("drift_model needs exactly one of hours= or sigma=")
+    if sigma is None:
+        sigma = rram.drift_sigma_increment(cfg, clock_offset, hours)
     n_drifted = 0
 
     def leaf(path, x):
@@ -162,12 +170,11 @@ def drift_model(
         if not isinstance(x, rram.CrossbarWeight):
             return x
         n_drifted += 1
+        if isinstance(sigma, (int, float)) and sigma <= 0.0:
+            return x
         h = jnp.uint32(zlib.crc32(_path_str(path).encode()))
         k = jax.random.fold_in(key, h)
-        return rram.apply_drift(
-            x, cfg, k, hours=hours, clock_offset=clock_offset,
-            event_index=event_index, sigma=sigma,
-        )
+        return _drift_leaf(x, cfg, k, sigma, event_index)
 
     out = jax.tree_util.tree_map_with_path(
         leaf, base, is_leaf=lambda n: isinstance(n, rram.CrossbarWeight)
@@ -178,6 +185,14 @@ def drift_model(
             "got a float tree — program with mode='codes' first"
         )
     return out
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _drift_leaf(xw, cfg, key, sigma, event_index):
+    """One leaf's drift tick, compiled for the same reason as
+    ``program_leaf``: op by op it would hold several full-size f32
+    temporaries of a stacked leaf at once."""
+    return rram.apply_drift(xw, cfg, key, sigma=sigma, event_index=event_index)
 
 
 def rram_bytes(base: Pytree) -> int:
@@ -532,7 +547,7 @@ def make_cached_calib_loss(cfg):
                     return (acc + T._mse(fout, s_out), idx + 1), None
 
                 (loss, _), _ = jax.lax.scan(
-                    enc_pair, (loss, 0),
+                    T._maybe_remat(enc_pair, cfg), (loss, 0),
                     (sbase["encoder"], adapters.get("encoder")),
                 )
             n_terms += cfg.encoder_layers
@@ -571,8 +586,10 @@ def make_cached_calib_loss(cfg):
                     acc = acc + T._mse(fout, s_out)
                 return (acc, idx + 1), None
 
+            # remat as the fused loss does: without it the backward pass
+            # keeps every layer's dequantized f32 weights alive at once
             (loss, _), _ = jax.lax.scan(
-                group, (loss, 0),
+                T._maybe_remat(group, cfg), (loss, 0),
                 (sbase["body"], adapters.get("body")),
             )
             n_terms += n_groups * p
@@ -614,6 +631,44 @@ def make_cached_calib_step(cfg, opt: AdamW = AdamW(lr=1e-3)):
         return new_state, {"loss": loss}
 
     return step
+
+
+def calib_step_program(step):
+    """The program every caller compiles a calibration step as: the
+    frozen teacher and student bases enter it but never leave it.
+
+    ``program(teacher_base, student_base, (adapters, opt_state, step),
+    *args)`` returns ``((adapters, opt_state, step), *rest)`` for a
+    ``step(state, *args) -> (CalibState, *rest)``. A compiled step that
+    returned the whole ``CalibState`` would write a fresh copy of both
+    bases (several GB at full width) on every step."""
+
+    def program(teacher_base, student_base, trainable, *args):
+        new, *rest = step(CalibState(teacher_base, student_base, *trainable),
+                          *args)
+        return ((new.adapters, new.opt_state, new.step), *rest)
+
+    return program
+
+
+def jit_calib_step(step, *, wrap=None):
+    """``jax.jit`` of ``calib_step_program(step)`` behind the
+    ``step(state, *args)`` signature. ``wrap`` transforms the program
+    before it is jitted (the fleet vmaps it over chips or shard_maps it
+    over a mesh)."""
+    program = calib_step_program(step)
+    jitted = jax.jit(program if wrap is None else wrap(program))
+
+    def run(state: CalibState, *args):
+        trainable, *rest = jitted(
+            state.teacher_base, state.student_base,
+            (state.adapters, state.opt_state, state.step), *args,
+        )
+        return (CalibState(state.teacher_base, state.student_base,
+                           *trainable), *rest)
+
+    run._cache_size = jitted._cache_size   # compile count, as on a jit
+    return run
 
 
 def make_calib_step(

@@ -45,6 +45,7 @@ from repro.core.calibrate import (
     CalibState,
     calibrated_fraction,
     drift_model,
+    jit_calib_step,
     make_cached_calib_step,
     make_calib_step,
     merge_adapters_for_serve,
@@ -376,10 +377,10 @@ class Deployment:
         with backend_ctx:
             if use_cached:
                 feats = teacher_features(self.teacher_base, batch, cfg)
-                step_fn = jax.jit(make_cached_calib_step(cfg, opt))
+                step_fn = jit_calib_step(make_cached_calib_step(cfg, opt))
                 run = lambda s: step_fn(s, feats, batch)
             else:
-                step_fn = jax.jit(make_calib_step(cfg, opt))
+                step_fn = jit_calib_step(make_calib_step(cfg, opt))
                 run = lambda s: step_fn(s, batch)
             for _ in range(steps):
                 state, metrics = run(state)

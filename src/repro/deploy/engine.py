@@ -88,6 +88,11 @@ class Request:
     enc_embeds: Optional[np.ndarray] = None    # (s_src, d) [enc-dec]
     patch_embeds: Optional[np.ndarray] = None  # (P, d) [vision prefix]
     tokens: List[int] = dataclasses.field(default_factory=list)
+    # with ``submit(keep_logits=True)``: the (V,) f32 logits each token of
+    # ``tokens`` was drawn from, copied to the host
+    logits: Optional[List[np.ndarray]] = dataclasses.field(
+        default=None, repr=False
+    )
     done: bool = False
     slot: Optional[int] = None       # None while queued / after retiring
     admitted_tick: Optional[int] = None
@@ -179,14 +184,16 @@ class ServeEngine:
     def submit(
         self, prompt, *, max_new: int = 16, temperature: float = 0.0,
         key: Optional[jax.Array] = None, eos_id: Optional[int] = None,
-        enc_embeds=None, patch_embeds=None,
+        enc_embeds=None, patch_embeds=None, keep_logits: bool = False,
     ) -> Request:
         """Enqueue a request; admission starts immediately if a slot is
         free (a single-chunk prompt gets its first token before this
         returns; longer prompts advance one chunk per ``step()``).
         ``prompt`` is a (s,) or (1, s) int token array; ``enc_embeds``
         (s_src, d) for encoder-decoder configs, ``patch_embeds`` (P, d)
-        for vision-prefix configs (leading batch dim of 1 accepted)."""
+        for vision-prefix configs (leading batch dim of 1 accepted).
+        ``keep_logits`` records on ``Request.logits`` the logits every
+        emitted token was sampled from."""
         serving._check_sampling_args(temperature, key)
         if max_new < 1:
             raise ValueError(f"max_new must be >= 1, got {max_new}")
@@ -235,6 +242,7 @@ class ServeEngine:
             temperature=float(temperature), key=key, eos_id=eos_id,
             enc_embeds=enc_embeds, patch_embeds=patch_embeds,
             submitted_at=time.perf_counter(),
+            logits=[] if keep_logits else None,
         )
         self._next_rid += 1
         self.pending.append(req)
@@ -367,6 +375,7 @@ class ServeEngine:
             req._logits, req.temperature, req.key
         )
         first = int(np.asarray(tok)[0, 0])
+        _keep_logits(req, req._logits)
         req.ttft_seconds = time.perf_counter() - req.submitted_at
         req.tokens.append(first)
         req.admitted_tick = self.tick
@@ -483,6 +492,7 @@ class ServeEngine:
                 logits[slot : slot + 1], req.temperature, req.key
             )
             t = int(np.asarray(tok)[0, 0])
+            _keep_logits(req, logits[slot : slot + 1])
             req.tokens.append(t)
             self.pos[slot] += 1
             self.last_tok[slot, 0] = t
@@ -641,6 +651,13 @@ class ServeEngine:
             ),
             "compile_count": self.compile_count(),
         }
+
+
+def _keep_logits(req: Request, logits) -> None:
+    """Append the last position of (1, s, V) ``logits`` to ``req.logits``
+    when the request asked for them."""
+    if req.logits is not None:
+        req.logits.append(np.asarray(logits[0, -1], np.float32))
 
 
 def _pow2_ceil(n: int) -> int:

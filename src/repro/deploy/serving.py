@@ -26,7 +26,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import substrate
@@ -116,12 +115,12 @@ def decode_step_fn(cfg, mesh: Optional[Mesh] = None, params=None):
 
     def build():
         specs = substrate.serve_param_specs(params)
-        sm = shard_map(
+        sm = jax.shard_map(
             lambda p, c, t, i: T.decode_step(p, c, t, i, cfg),
             mesh=mesh,
             in_specs=(specs, P(), P(), P()),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sm)
 
@@ -156,12 +155,12 @@ def prefill_fn(cfg, mesh: Optional[Mesh] = None, params=None):
                 raise ValueError(
                     "mesh serving is decoder-only (no enc_embeds/patch_embeds)"
                 )
-            sm = shard_map(
+            sm = jax.shard_map(
                 lambda p, t: T.prefill(p, t, cfg, max_len, None),
                 mesh=mesh,
                 in_specs=(specs, P()),
                 out_specs=(P(), P()),
-                check_rep=False,
+                check_vma=False,
             )
             return sm(p, t)
 
@@ -195,14 +194,14 @@ def prefill_chunk_fn(cfg, mesh: Optional[Mesh] = None, params=None):
         specs = substrate.serve_param_specs(params)
 
         def fn(p, t, c, pos0, nv, max_len, prefix=0):
-            sm = shard_map(
+            sm = jax.shard_map(
                 lambda p, t, c, pos0, nv: T.prefill_chunk(
                     p, t, c, pos0, nv, cfg, max_len, prefix
                 ),
                 mesh=mesh,
                 in_specs=(specs, P(), P(), P(), P()),
                 out_specs=(P(), P()),
-                check_rep=False,
+                check_vma=False,
             )
             return sm(p, t, c, pos0, nv)
 

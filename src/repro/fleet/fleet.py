@@ -48,7 +48,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import substrate
@@ -57,6 +56,7 @@ from repro.core import calibrate as C
 from repro.core import rram
 from repro.core.calibrate import (
     CalibState,
+    jit_calib_step,
     make_cached_calib_loss,
     make_cached_calib_step,
     make_calib_step,
@@ -194,23 +194,17 @@ def _calib_step_fn(cfg, opt: AdamW, kind: str, axes: Pytree):
     """The jitted vmapped calibration step for ``(kind, cfg, opt, active
     backend)``: chip axis on student base / adapters / optimizer / step,
     teacher base and batch broadcast."""
-    in_state = CalibState(None, axes, 0, 0, 0)
-    out_state = CalibState(None, axes, 0, 0, 0)
+    n_args = 2 if kind == "cached" else 1
 
-    def build_cached():
-        step = make_cached_calib_step(cfg, opt)
-        return jax.jit(jax.vmap(
-            step, in_axes=(in_state, None, None), out_axes=(out_state, 0)
-        ))
-
-    def build_full():
-        step = make_calib_step(cfg, opt)
-        return jax.jit(jax.vmap(
-            step, in_axes=(in_state, None), out_axes=(out_state, 0)
+    def build():
+        step = (make_cached_calib_step if kind == "cached"
+                else make_calib_step)(cfg, opt)
+        return jit_calib_step(step, wrap=lambda program: jax.vmap(
+            program, in_axes=(None, axes, 0) + (None,) * n_args,
         ))
 
     key = (kind, cfg, opt, substrate.active_backend_name())
-    return _registry_get(key, build_cached if kind == "cached" else build_full)
+    return _registry_get(key, build)
 
 
 def _axes_to_specs(axes_tree: Pytree) -> Pytree:
@@ -266,7 +260,7 @@ def _mesh_calib_step_fn(cfg, opt: AdamW, axes: Pytree, mesh):
     they are comparable step-for-step against the dense path)."""
     loss_fn = make_cached_calib_loss(cfg)
     n_dev = int(mesh.shape["data"])
-    state_specs = CalibState(P(), _axes_to_specs(axes), P("data"), P("data"), P("data"))
+    trainable = (P("data"), P("data"), P("data"))
 
     def build():
         def body(state, feats, batch, residual):
@@ -314,13 +308,13 @@ def _mesh_calib_step_fn(cfg, opt: AdamW, axes: Pytree, mesh):
             )
             return new_state, {"loss": loss_full}, new_residual
 
-        sm = shard_map(
-            body, mesh=mesh,
-            in_specs=(state_specs, P(), P(), P("data")),
-            out_specs=(state_specs, P(), P("data")),
-            check_rep=False,
-        )
-        return jax.jit(sm)
+        return jit_calib_step(body, wrap=lambda program: jax.shard_map(
+            program, mesh=mesh,
+            in_specs=(P(), _axes_to_specs(axes), trainable, P(), P(),
+                      P("data")),
+            out_specs=(trainable, P(), P("data")),
+            check_vma=False,
+        ))
 
     key = ("mesh_compressed", cfg, opt, substrate.active_backend_name(), mesh)
     return _registry_get(key, build)

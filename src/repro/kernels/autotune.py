@@ -2,15 +2,14 @@
 
 Replaces the hardcoded 128s in ``substrate/exec.py``: given the true
 problem shape ``(M, K, N, r)`` the tuner picks ``(bm, bn, bk)`` and the
-padded operand extents, driven by the same hardware constants the
-roofline planner uses (``launch/roofline.py``):
+padded operand extents:
 
-* The MXU ridge point is ``PEAK_FLOPS / HBM_BW`` (~240 flop/byte on
-  v5e). A decode call streams 2 bytes of codes per weight and performs
-  ``2*M`` flops per weight, so any M below ``ridge/2`` (~120 rows) is
-  memory-bound — the tile choice there minimizes grid bookkeeping and
-  streams the codes exactly once: a single sublane-aligned M block
-  (the GEMV variant) with the largest ``(bk, bn)`` that fits VMEM.
+* A decode call streams 2 bytes of codes per weight and performs
+  ``2*M`` flops per weight, so at a handful of rows (``M <=
+  GEMV_MAX_M``) it is bound by streaming the codes — the tile choice
+  there minimizes grid bookkeeping and streams the codes exactly once:
+  a single sublane-aligned M block (the GEMV variant) with the largest
+  ``(bk, bn)`` that fits VMEM.
 * At prefill shapes (M >= 128) the kernel is compute-bound and tiles at
   the 128x128 MXU granule; ``bk``/``bn`` still grow to the VMEM budget
   so each x tile is revisited as few times as possible.
@@ -26,11 +25,6 @@ dispatch at trace time is a dict lookup.
 from __future__ import annotations
 
 from typing import Dict, NamedTuple, Tuple
-
-from repro.launch.roofline import HBM_BW, PEAK_FLOPS
-
-# flop/byte above which the MXU, not HBM, bounds the kernel
-RIDGE_FLOPS_PER_BYTE = PEAK_FLOPS / HBM_BW
 
 # VMEM working-set budget per grid cell: half of the 16 MiB/core so the
 # pipeline can double-buffer the next block while computing.
@@ -92,7 +86,7 @@ def _vmem_bytes(bm: int, bn: int, bk: int, r: int, int8: bool) -> int:
 
 def select_tiles(
     m: int, k: int, n: int, r: int, *,
-    interpret: bool = True, int8: bool = False,
+    interpret: bool, int8: bool = False,
 ) -> TilePlan:
     """Pick ``(bm, bn, bk)`` + padded extents for a ``(M, K, N, r)`` fused
     crossbar linear. Memoized — see module docstring for the policy."""
@@ -117,8 +111,8 @@ def select_tiles(
         k_pad = _round_up(k, _LANE)
         n_pad = _round_up(n, _LANE)
         if m <= GEMV_MAX_M:
-            # memory-bound region (M << ridge/2): one sublane-aligned M
-            # block, codes streamed once through the K-parallel grid.
+            # code-streaming-bound region: one sublane-aligned M block,
+            # codes streamed once through the K-parallel grid.
             bm = _round_up(m, sublane)
             m_pad = bm
         else:

@@ -37,7 +37,11 @@ def _kernel(x_ref, gp_ref, gn_ref, scale_ref, o_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.float32)
-    w = gp_ref[...].astype(jnp.float32) - gn_ref[...].astype(jnp.float32)
+    # u8 -> i32 -> f32: Mosaic has no direct u8 -> f32 cast; the
+    # differential pair is exact in i32 and in f32.
+    w = (gp_ref[...].astype(jnp.int32) - gn_ref[...].astype(jnp.int32)).astype(
+        jnp.float32
+    )
     cur = jax.lax.dot(x, w, preferred_element_type=jnp.float32)
     # per-tile ADC: full scale tracks the tile's input magnitude (the DAC
     # reference), matching core/rram.py::mvm_reference exactly.

@@ -53,8 +53,12 @@ def _kernel(x_ref, gp_ref, gn_ref, scale_ref, a_ref, b_ref, gamma_ref,
         xa_ref[...] = jnp.zeros_like(xa_ref)
 
     x = x_ref[...]
-    # in-register differential dequant: int8 codes -> f32 weights
-    w = (gp_ref[...].astype(jnp.float32) - gn_ref[...].astype(jnp.float32))
+    # in-register differential dequant: u8 codes -> f32 weights. Mosaic
+    # has no direct u8 -> f32 cast, so the pair widens to i32 first; the
+    # difference (|G+ - G-| <= 255) is exact in i32 and in f32.
+    w = (gp_ref[...].astype(jnp.int32) - gn_ref[...].astype(jnp.int32)).astype(
+        jnp.float32
+    )
     acc_ref[...] += jax.lax.dot(
         x.astype(jnp.float32), w, preferred_element_type=jnp.float32
     )

@@ -24,7 +24,7 @@ import jax.numpy as jnp  # noqa: E402
 from repro import deploy  # noqa: E402
 from repro.configs import ARCH_IDS, get_arch, input_specs  # noqa: E402
 from repro.configs.shapes import ArchSpec, ShapeSpec  # noqa: E402
-from repro.core.calibrate import CalibState, make_calib_step  # noqa: E402
+from repro.core.calibrate import calib_step_program, make_calib_step  # noqa: E402
 from repro.launch import mesh as mesh_lib  # noqa: E402
 from repro.launch.roofline import Roofline, collective_bytes  # noqa: E402
 from repro.models import transformer as T  # noqa: E402
@@ -76,21 +76,21 @@ def build_step(arch: ArchSpec, shape: ShapeSpec, mesh, *, smoke=False,
 
     if shape.kind == "train":
         opt = AdamW(lr=1e-3)
-        step_fn = make_calib_step(cfg, opt)
+        program = calib_step_program(make_calib_step(cfg, opt))
         state_abs = deploy.abstract_calib_state(cfg, params_abs)
         opt_abs = state_abs.opt_state
         opt_sh = sh.tree_shardings(opt_abs, mesh, (), dp=dp, tp=tp)
         step_sh = sh.tree_shardings(
             jax.ShapeDtypeStruct((), jnp.int32), mesh, (), dp=dp, tp=tp
         )
-        state_sh = CalibState(
-            p_sh["base"], p_sh["base"], p_sh["adapters"], opt_sh, step_sh
-        )
+        trainable_abs = (state_abs.adapters, opt_abs, state_abs.step)
+        trainable_sh = (p_sh["adapters"], opt_sh, step_sh)
         return (
-            step_fn,
-            (state_abs, batch_abs),
-            (state_sh, b_sh),
-            (state_sh, None),
+            program,
+            (state_abs.teacher_base, state_abs.student_base, trainable_abs,
+             batch_abs),
+            (p_sh["base"], p_sh["base"], trainable_sh, b_sh),
+            (trainable_sh, None),
             params_abs,
         )
 
@@ -149,10 +149,6 @@ def _compile_once(arch, cfg, shape, mesh, *, smoke=False):
 
 def _extract(compiled) -> Dict:
     cost = compiled.cost_analysis()
-    # jax 0.4.x returns a one-element list of per-program dicts; >=0.5
-    # returns the dict directly.
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         peak = getattr(mem, "peak_memory_in_bytes", None)

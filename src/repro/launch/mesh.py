@@ -15,13 +15,10 @@ from jax.sharding import Mesh
 
 
 def mesh_context(mesh):
-    """Ambient-mesh context manager across jax versions: ``jax.set_mesh``
-    where it exists (>= 0.6), else the legacy ``Mesh`` context manager
-    (0.4.x global mesh) — both make the mesh visible to
-    ``with_sharding_constraint`` / shard_hint inside jitted bodies."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Ambient-mesh context manager (``jax.set_mesh``): makes the mesh
+    visible to ``with_sharding_constraint`` / shard_hint inside jitted
+    bodies."""
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 
 from repro import deploy
 from repro.configs import get_arch
+from repro.launch.compile_cache import setup_compile_cache
 
 # Re-exported lifecycle pieces (deprecated import path; use repro.deploy).
 BACKENDS = deploy.BACKENDS
@@ -85,6 +86,7 @@ def main():
              "devices — on CPU set XLA_FLAGS device forcing first)",
     )
     args = ap.parse_args()
+    setup_compile_cache()
     arch = get_arch(args.arch)
     cfg = arch.smoke if args.smoke else arch.full
 

@@ -25,9 +25,10 @@ import numpy as np
 from repro import deploy
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_arch
-from repro.core.calibrate import CalibState, make_calib_step
+from repro.core.calibrate import CalibState, jit_calib_step, make_calib_step
 from repro.data.pipeline import DataConfig, global_batch_at_step
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import setup_compile_cache
 from repro.optim.adam import AdamW
 from repro.runtime.fault import PreemptionGuard, StepTimer, StragglerDetector
 from repro.sharding import rules as sh
@@ -135,7 +136,7 @@ def train(
     # NOTE: no donation — teacher and student share digital-peripheral
     # buffers (norms/embeddings pass through program_model unchanged), and
     # XLA rejects donating the same buffer twice.
-    jit_step = jax.jit(step_fn)
+    jit_step = jit_calib_step(step_fn)
     detector = StragglerDetector()
     history = []
     feats_cache = {}
@@ -209,6 +210,7 @@ def main():
         help="substrate representation of the programmed student",
     )
     args = ap.parse_args()
+    setup_compile_cache()
     out = train(
         args.arch, smoke=args.smoke, steps=args.steps, batch=args.batch,
         seq=args.seq, lr=args.lr, ckpt_dir=args.ckpt_dir,

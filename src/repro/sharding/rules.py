@@ -114,10 +114,7 @@ def shard_hint(x: jax.Array, *spec) -> jax.Array:
     for dim, s in zip(x.shape, spec):
         a = axes.get(s) if isinstance(s, str) else None
         resolved.append(_fit(a, dim))
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*resolved))
-    except Exception:
-        return x  # no ambient mesh
+    return jax.lax.with_sharding_constraint(x, P(*resolved))
 
 
 def _fit(axis, dim):
@@ -133,17 +130,8 @@ def _fit(axis, dim):
 
 
 def _ambient_mesh():
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        m = get_abstract()
-        return None if m is None or m.empty else m
-    try:  # jax 0.4.x: legacy global mesh set by the Mesh context manager
-        from jax.interpreters import pxla
-
-        m = pxla.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _as_tuple(a):

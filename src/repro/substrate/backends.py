@@ -7,7 +7,7 @@ is *never rewritten*. What varies is how a forward pass reads it:
   * ``codes``     — the deployment path. Codes stay resident (uint8 in
                     HBM); the fused ``dora_linear`` Pallas kernel
                     dequantizes in-register per tile and applies the
-                    DoRA epilogue. ``interpret=True`` on CPU hosts.
+                    DoRA epilogue. Interpret mode on CPU hosts.
   * ``dequant``   — read the array back to floats per call and run the
                     plain jnp path. Differentiable w.r.t. the adapters,
                     so calibration/training over a codes-resident
@@ -220,25 +220,18 @@ class CodesBackend(Backend):
             # the ordinary prepared kernel on this device's column
             # slice, then the zero-scatter psum epilogue rebuilds the
             # full activation bitwise (columns are disjoint).
-            y = rimc_linear_prepared(
-                x, xw.local, interpret=X.default_interpret(), accum=accum
-            )
+            y = rimc_linear_prepared(x, xw.local, accum=accum)
             return tp_column_allgather(y, xw.n_total, xw.axis)
         if isinstance(xw, PreparedCrossbar):
             # serve-time prepared leaf: operands already padded/fused
             # (+ s8-recoded for int8); per-call work is the x pad only
-            return rimc_linear_prepared(
-                x, xw, interpret=X.default_interpret(), accum=accum
-            )
+            return rimc_linear_prepared(x, xw, accum=accum)
         gamma = _gamma_for(xw, adapter, acfg)
         if not adapter or acfg.kind == "none":
             adapter = _zero_adapter(xw.g_pos.shape[-2], xw.g_pos.shape[-1])
         if gamma is None:
             gamma = jnp.ones((1, xw.g_pos.shape[-1]), jnp.float32)
-        return X.rimc_linear(
-            x, xw, adapter, gamma, interpret=X.default_interpret(),
-            accum=accum,
-        )
+        return X.rimc_linear(x, xw, adapter, gamma, accum=accum)
 
 
 _ADC_DEFAULTS = RramConfig()
@@ -292,10 +285,7 @@ class CodesAdcBackend(Backend):
                 "codes_adc reads raw per-leaf codes; prepared (fused/"
                 "padded) trees are codes-backend serving artifacts"
             )
-        y = X.rimc_mvm_adc(
-            x, xw, code_max=code_max, adc_bits=adc_bits,
-            interpret=X.default_interpret(),
-        )
+        y = X.rimc_mvm_adc(x, xw, code_max=code_max, adc_bits=adc_bits)
         y = y.astype(jnp.float32)
         if adapter and "lora_a" in adapter:
             a = adapter["lora_a"].astype(jnp.float32)

@@ -28,8 +28,18 @@ from repro.kernels.crossbar_mvm import crossbar_mvm
 
 
 def default_interpret() -> bool:
-    """Pallas interpret mode everywhere except a real TPU backend."""
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode on a CPU host (tests, CPU runs), Mosaic on a
+    TPU. Any other backend has no lowering for these kernels and raises
+    rather than falling back to the interpreter."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the crossbar kernels run on a TPU (Mosaic) or a CPU host "
+        f"(interpret mode); JAX's default backend is {backend!r}"
+    )
 
 
 def _pad_to(x, mult, axis):
@@ -104,7 +114,7 @@ def rimc_linear(
     bm: Optional[int] = None,
     bn: Optional[int] = None,
     bk: Optional[int] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     accum: str = "f32",
 ) -> jax.Array:
     """Fused Y = gamma * (X W_r + (XA)B) with autotuned tile selection.
@@ -112,7 +122,10 @@ def rimc_linear(
     analytic plan for (M, K, N, r) (``kernels/autotune.py``); explicit
     ``bm``/``bn``/``bk`` override it (operands pad up to any choice, so
     the output is block-size invariant — pinned by a hypothesis test).
-    ``accum="int8"`` selects the integer MMA path."""
+    ``accum="int8"`` selects the integer MMA path; ``interpret`` defaults
+    to ``default_interpret()``."""
+    if interpret is None:
+        interpret = default_interpret()
     lead = x.shape[:-1]
     k = x.shape[-1]
     n = xw.g_pos.shape[-1]
@@ -157,9 +170,11 @@ def rimc_mvm_adc(
     adc_bits: int = 8,
     bm: int = 128,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """ADC-faithful crossbar MVM (no adapter): analog fidelity studies."""
+    if interpret is None:
+        interpret = default_interpret()
     lead = x.shape[:-1]
     k = x.shape[-1]
     n = xw.g_pos.shape[-1]

@@ -193,11 +193,15 @@ def prepared_ref_forward(x: jax.Array, prep: PreparedCrossbar) -> jax.Array:
 def rimc_linear_prepared(
     x: jax.Array, prep: PreparedCrossbar, *,
     bm: Optional[int] = None, bn: Optional[int] = None,
-    bk: Optional[int] = None, interpret: bool = True, accum: str = "f32",
+    bk: Optional[int] = None, interpret: Optional[bool] = None,
+    accum: str = "f32",
 ) -> jax.Array:
     """Hot-path fused linear over prepared operands: the only per-call
     tensor work besides the kernel is padding x (rows to the M block,
-    cols to the prepared K) — a no-op in interpret mode."""
+    cols to the prepared K) — a no-op in interpret mode. ``interpret``
+    defaults to ``default_interpret()``."""
+    if interpret is None:
+        interpret = X.default_interpret()
     lead = x.shape[:-1]
     xf = x.reshape(-1, x.shape[-1])
     m = xf.shape[0]

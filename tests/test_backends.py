@@ -182,7 +182,7 @@ def test_forward_parity_codes_vs_dequant(arch_id, tol):
 def test_calibration_step_runs_on_resident_codes():
     """Training over a codes-resident student via the differentiable
     dequant backend: loss finite, adapters update, codes frozen."""
-    from repro.core.calibrate import CalibState, make_calib_step
+    from repro.core.calibrate import CalibState, jit_calib_step, make_calib_step
     from repro.optim.adam import AdamW, adamw_init
 
     cfg, params, codes, _ = _programmed_pair("qwen3_1_7b")
@@ -195,7 +195,7 @@ def test_calibration_step_runs_on_resident_codes():
     }
     step = make_calib_step(cfg, AdamW(lr=1e-3))
     with substrate.use_backend("dequant"):
-        new_state, metrics = jax.jit(step)(state, batch)
+        new_state, metrics = jit_calib_step(step)(state, batch)
     assert np.isfinite(float(metrics["loss"]))
     diff = jax.tree_util.tree_map(
         lambda a, b: float(jnp.abs(a - b).sum()),

@@ -171,7 +171,7 @@ def test_cached_calib_step_matches_fused_loss(arch_id):
         adamw_init(params["adapters"]), jnp.zeros((), jnp.int32),
     )
     step = calibrate.make_cached_calib_step(cfg)
-    _, metrics = jax.jit(step)(state, feats, batch)
+    _, metrics = calibrate.jit_calib_step(step)(state, feats, batch)
     # bf16 block outputs re-round under different XLA programs; the
     # per-term structure is identical (enc pairs + dec pairs + lm_head,
     # averaged over n_terms)

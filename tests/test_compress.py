@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.optim.compress import allreduce_compressed, compress, init_residual
@@ -27,10 +27,10 @@ def _one_device_step():
         return allreduce_compressed(g, r, "data")
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P()), out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
     )
 

@@ -117,14 +117,24 @@ def test_slot_recycling_and_eos():
         jax.random.randint(jax.random.PRNGKey(3), (6,), 0, cfg.vocab)
     )
     key = jax.random.PRNGKey(7)
-    ref = _reference(session, prompt, 8, temperature=1.0, key=key)
+    # The smoke model ties lm_head to a unit-variance embedding, so the
+    # logit of the token just fed in leads by ~d_model against ~sqrt(d_model)
+    # and a temperature-1 stream repeats its first token forever. Sampling
+    # at temperature d_model flattens that lead, so the stream draws
+    # near-uniformly over the vocab and new tokens appear mid-stream.
+    temperature = float(cfg.d_model)
+    ref = _reference(session, prompt, 8, temperature=temperature, key=key)
     # eos = the first token value whose FIRST occurrence is at index >= 2:
     # the engine must stop there (token included) and free the slot for
     # the queued second request
-    j = next(i for i in range(2, len(ref)) if ref[i] not in ref[:i])
+    j = next((i for i in range(2, len(ref)) if ref[i] not in ref[:i]), None)
+    assert j is not None, (
+        f"premise: the reference stream {ref} has no token that first "
+        "appears at index >= 2, so no mid-stream EOS can be chosen"
+    )
     engine = ServeEngine(session, max_slots=1, max_len=24)
     r1 = engine.submit(
-        prompt, max_new=8, temperature=1.0, key=key, eos_id=ref[j]
+        prompt, max_new=8, temperature=temperature, key=key, eos_id=ref[j]
     )
     r2 = engine.submit(prompt + 1, max_new=3)
     assert r2.slot is None and engine.pending  # queued: no free slot
@@ -459,3 +469,40 @@ def test_engine_accounting_unified_retirement():
     assert st["completed"] == 3
     assert st["generated_tokens"] == st["first_tokens"] + st["decode_tokens"]
     assert st["generated_tokens"] == emitted
+
+
+def test_keep_logits_match_the_forward_reference():
+    """``submit(keep_logits=True)`` hands back the logits each emitted
+    token was drawn from. Through chunked admission and ragged batched
+    decode they agree with a plain ``T.forward`` over prompt + emitted
+    tokens (dequant backend, precision "highest"; 0.05 is the bound
+    tests/test_backends.py holds codes vs dequant to), and each greedy
+    token is their argmax. Without the option nothing is kept."""
+    from repro import substrate
+
+    cfg = get_arch("qwen3_1_7b").smoke
+    dep = Deployment.program(cfg, 0, backend="codes")
+    session = dep.serve()
+    engine = ServeEngine(session, max_slots=2, max_len=64, prefill_chunk=16)
+    prompts = [
+        np.asarray(jax.random.randint(jax.random.PRNGKey(60 + i), (n,), 0,
+                                      cfg.vocab))
+        for i, n in enumerate((5, 37, 50))
+    ]
+    reqs = [engine.submit(p, max_new=6, keep_logits=True) for p in prompts]
+    plain = engine.submit(prompts[0], max_new=2)
+    engine.run()
+    assert plain.done and plain.logits is None
+    fwd = jax.jit(lambda p, t: T.forward(p, {"tokens": t}, cfg))
+    for req in reqs:
+        assert req.done and len(req.logits) == len(req.tokens) == 6
+        got = np.stack(req.logits).astype(np.float64)
+        assert list(got.argmax(-1)) == req.tokens
+        seq = np.concatenate([req.prompt, req.tokens[:-1]]).astype(np.int32)
+        with substrate.use_backend("dequant"), \
+                jax.default_matmul_precision("highest"):
+            ref = fwd({"base": dep.base, "adapters": dep.adapters},
+                      jnp.asarray(seq)[None])
+        ref = np.asarray(ref[0, req.prompt_len - 1:], np.float64)
+        err = np.linalg.norm(got - ref) / np.linalg.norm(ref)
+        assert err < 0.05, f"request {req.rid}: rel. error {err}"

@@ -248,3 +248,19 @@ def test_autotune_tpu_plans_aligned_and_within_budget():
         ) <= autotune.VMEM_BUDGET_BYTES
         if m <= autotune.GEMV_MAX_M:
             assert plan.gemv and plan.bm < 128
+
+
+@pytest.mark.parametrize("backend,want", [
+    ("cpu", True), ("tpu", False), ("gpu", None),
+])
+def test_default_interpret_per_backend(monkeypatch, backend, want):
+    """Interpret mode on a CPU host, Mosaic on a TPU, and no silent
+    interpreter fallback on any other backend."""
+    from repro.substrate import exec as X
+
+    monkeypatch.setattr(X.jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            X.default_interpret()
+    else:
+        assert X.default_interpret() is want

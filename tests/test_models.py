@@ -12,7 +12,7 @@ import pytest
 
 from repro.configs import ARCH_IDS, get_arch
 from repro.core import calibrate as C
-from repro.core.calibrate import CalibState, make_calib_step
+from repro.core.calibrate import CalibState, jit_calib_step, make_calib_step
 from repro.models import transformer as T
 from repro.optim.adam import AdamW, adamw_init
 
@@ -60,7 +60,7 @@ def test_smoke_calibration_step(arch_id):
         adamw_init(params["adapters"]), jnp.zeros((), jnp.int32),
     )
     step = make_calib_step(cfg, AdamW(lr=1e-3))
-    new_state, metrics = jax.jit(step)(state, _batch(cfg))
+    new_state, metrics = jit_calib_step(step)(state, _batch(cfg))
     loss = float(metrics["loss"])
     assert np.isfinite(loss) and loss > 0
     # adapters changed

@@ -123,3 +123,16 @@ def test_smoke_configs_resolve_without_error(arch):
         assert len(spec) <= x.ndim
 
     jax.tree_util.tree_map_with_path(leaf, base)
+
+
+def test_shard_hint_is_identity_without_logical_axes():
+    x = jnp.ones((4, 8))
+    assert R.shard_hint(x, "D", "T") is x
+
+
+def test_shard_hint_raises_without_a_mesh():
+    """Logical axes bound with no ambient mesh is a caller error; the
+    constraint's failure surfaces instead of returning ``x`` unsharded."""
+    with R.logical_axes(("data",), "model"):
+        with pytest.raises(RuntimeError, match="mesh"):
+            R.shard_hint(jnp.ones((4, 8)), "D", "T")
