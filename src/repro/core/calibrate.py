@@ -641,14 +641,15 @@ def calib_step_program(step):
     *args)`` returns ``((adapters, opt_state, step), *rest)`` for a
     ``step(state, *args) -> (CalibState, *rest)``. A compiled step that
     returned the whole ``CalibState`` would write a fresh copy of both
-    bases (several GB at full width) on every step."""
+    bases (several GB at full width) on every step. Jitted, it is the
+    module ``jit_calib_step``."""
 
-    def program(teacher_base, student_base, trainable, *args):
+    def calib_step(teacher_base, student_base, trainable, *args):
         new, *rest = step(CalibState(teacher_base, student_base, *trainable),
                           *args)
         return ((new.adapters, new.opt_state, new.step), *rest)
 
-    return program
+    return calib_step
 
 
 def jit_calib_step(step, *, wrap=None):

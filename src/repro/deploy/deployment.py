@@ -361,30 +361,39 @@ class Deployment:
 
         cfg = self.cfg
         opt = opt if opt is not None else AdamW(lr=lr)
-        warm_rec = None
-        if registry is not None and warm_start:
-            from repro.registry.warmstart import seed_deployment
-
-            warm_rec = seed_deployment(self, registry)
-        batch = self._calibration_batch(batch_or_samples, seq_len)
-        use_cached = True if cached_teacher is None else bool(cached_teacher)
-        state = self.calib_state()
         backend_ctx = (
             substrate.use_backend("dequant")
             if self.backend != "dequant" else contextlib.nullcontext()
         )
         losses: List[float] = []
+        # ``calibrate.prepare`` runs from entry until the first step has
+        # been dispatched; each ``calibrate.step`` holds one step's loss
+        # read (and, after the first, its call)
         with backend_ctx:
-            if use_cached:
-                feats = teacher_features(self.teacher_base, batch, cfg)
-                step_fn = jit_calib_step(make_cached_calib_step(cfg, opt))
-                run = lambda s: step_fn(s, feats, batch)
-            else:
-                step_fn = jit_calib_step(make_calib_step(cfg, opt))
-                run = lambda s: step_fn(s, batch)
-            for _ in range(steps):
-                state, metrics = run(state)
-                losses.append(float(metrics["loss"]))
+            with jax.profiler.TraceAnnotation("calibrate.prepare"):
+                warm_rec = None
+                if registry is not None and warm_start:
+                    from repro.registry.warmstart import seed_deployment
+
+                    warm_rec = seed_deployment(self, registry)
+                batch = self._calibration_batch(batch_or_samples, seq_len)
+                use_cached = (True if cached_teacher is None
+                              else bool(cached_teacher))
+                state = self.calib_state()
+                if use_cached:
+                    feats = teacher_features(self.teacher_base, batch, cfg)
+                    step_fn = jit_calib_step(make_cached_calib_step(cfg, opt))
+                    run = lambda s: step_fn(s, feats, batch)
+                else:
+                    step_fn = jit_calib_step(make_calib_step(cfg, opt))
+                    run = lambda s: step_fn(s, batch)
+                if steps > 0:
+                    state, metrics = run(state)
+            for i in range(steps):
+                with jax.profiler.TraceAnnotation("calibrate.step"):
+                    if i:
+                        state, metrics = run(state)
+                    losses.append(float(metrics["loss"]))
                 if loss_threshold and losses[-1] <= loss_threshold:
                     break
         self.adopt(state)

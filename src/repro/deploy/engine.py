@@ -48,6 +48,13 @@ all advanced by ONE compiled batched ``decode_step``:
   ``decode_tokens`` and ``completed`` always satisfy
   ``generated_tokens == first_tokens + decode_tokens`` (asserted in
   tests/test_engine.py).
+* **Trace spans.** Each phase of a tick opens a
+  ``jax.profiler.TraceAnnotation`` on the host: ``engine.admit`` (one
+  admission unit, tagged with the request's ``rid`` and ``slot``),
+  ``engine.slot_write`` inside it, ``engine.decode`` (the batched step's
+  dispatch) and ``engine.sample`` (the per-slot sampling loop). Under a
+  profiler session they share the device trace's clock; without one they
+  cost about a microsecond each and add no sync.
 
 Determinism: every row of the batched step computes exactly what a
 single-request ``serving.generate`` call computes (row-independent
@@ -325,29 +332,33 @@ class ServeEngine:
         req = self.slot_req[slot]
         if req is None or self.active[slot] or req.done:
             return
-        cfg = self.cfg
-        if req._vision_pending:
-            with self.session.scope():
-                req._cache = serving.prefill_vision_fn(cfg, self.session.mesh)(
-                    self.session.params,
-                    jnp.asarray(req.patch_embeds)[None], req._cache,
-                    self.max_len,
-                )
-            req._vision_pending = False
-            self.prefill_chunks += 1
-            if req._spans:
-                return  # text chunks continue on the next tick
-        elif req._spans:
-            a, b_ = req._spans.pop(0)
-            with self.session.scope():
-                req._logits, req._cache = self._chunk_call(
-                    req._cache, req.prompt, a, b_, req.vision_len
-                )
-            self.prefill_chunks += 1
-            self._store_prefix(req, b_)
-            if req._spans:
-                return
-        self._finalize_admission(slot, req)
+        with jax.profiler.TraceAnnotation(
+            "engine.admit", rid=req.rid, slot=int(slot)
+        ):
+            cfg = self.cfg
+            if req._vision_pending:
+                with self.session.scope():
+                    vision = serving.prefill_vision_fn(cfg, self.session.mesh)
+                    req._cache = vision(
+                        self.session.params,
+                        jnp.asarray(req.patch_embeds)[None], req._cache,
+                        self.max_len,
+                    )
+                req._vision_pending = False
+                self.prefill_chunks += 1
+                if req._spans:
+                    return  # text chunks continue on the next tick
+            elif req._spans:
+                a, b_ = req._spans.pop(0)
+                with self.session.scope():
+                    req._logits, req._cache = self._chunk_call(
+                        req._cache, req.prompt, a, b_, req.vision_len
+                    )
+                self.prefill_chunks += 1
+                self._store_prefix(req, b_)
+                if req._spans:
+                    return
+            self._finalize_admission(slot, req)
 
     def _chunk_call(self, cache, prompt, a, b_, vision_len):
         """One bucketed chunk step: tokens [a, b_) at absolute positions
@@ -387,7 +398,9 @@ class ServeEngine:
         if req.max_new <= 1 or (req.eos_id is not None and first == req.eos_id):
             self._finish(req, slot)  # nothing to decode — recycle the slot
             return
-        with self.session.scope():
+        with self.session.scope(), jax.profiler.TraceAnnotation(
+            "engine.slot_write"
+        ):
             self.cache = T.write_cache_slot(self.cache, one, slot)
         self.active[slot] = True
         self.pos[slot] = req.vision_len + req.prompt_len  # next write position
@@ -475,7 +488,9 @@ class ServeEngine:
                 self.tick += 1  # an admission-only tick still advances time
             return busy
         t0 = time.perf_counter()
-        with self.session.scope():
+        with self.session.scope(), jax.profiler.TraceAnnotation(
+            "engine.decode"
+        ):
             # fetch INSIDE the scope: the registry key includes the
             # active backend name, and codes vs codes_adc sessions share
             # identical param avals — a scope-blind fetch would let one
@@ -486,20 +501,22 @@ class ServeEngine:
                 jnp.asarray(self.last_tok), jnp.asarray(self.pos),
             )
         n_live = int(self.active.sum())
-        for slot in np.flatnonzero(self.active):
-            req = self.slot_req[slot]
-            tok, req.key = serving._next_token(
-                logits[slot : slot + 1], req.temperature, req.key
-            )
-            t = int(np.asarray(tok)[0, 0])
-            _keep_logits(req, logits[slot : slot + 1])
-            req.tokens.append(t)
-            self.pos[slot] += 1
-            self.last_tok[slot, 0] = t
-            hit_eos = req.eos_id is not None and t == req.eos_id
-            out_of_room = int(self.pos[slot]) + 1 >= self.max_len
-            if len(req.tokens) >= req.max_new or hit_eos or out_of_room:
-                self._finish(req, slot)
+        # the host waits here: for the step's logits, then slot by slot
+        with jax.profiler.TraceAnnotation("engine.sample"):
+            for slot in np.flatnonzero(self.active):
+                req = self.slot_req[slot]
+                tok, req.key = serving._next_token(
+                    logits[slot : slot + 1], req.temperature, req.key
+                )
+                t = int(np.asarray(tok)[0, 0])
+                _keep_logits(req, logits[slot : slot + 1])
+                req.tokens.append(t)
+                self.pos[slot] += 1
+                self.last_tok[slot, 0] = t
+                hit_eos = req.eos_id is not None and t == req.eos_id
+                out_of_room = int(self.pos[slot]) + 1 >= self.max_len
+                if len(req.tokens) >= req.max_new or hit_eos or out_of_room:
+                    self._finish(req, slot)
         self.decode_seconds += time.perf_counter() - t0
         self.decode_tokens += n_live
         self.tick += 1

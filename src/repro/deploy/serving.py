@@ -105,18 +105,18 @@ def decode_step_fn(cfg, mesh: Optional[Mesh] = None, params=None):
     from which leaves are actually wrapped."""
     from repro.models import transformer as T
 
+    def decode_step(p, c, t, i):   # the program's name: jit_decode_step
+        return T.decode_step(p, c, t, i, cfg)
+
     if mesh is None:
-        return _registry_get(
-            "decode", cfg,
-            lambda: jax.jit(lambda p, c, t, i: T.decode_step(p, c, t, i, cfg)),
-        )
+        return _registry_get("decode", cfg, lambda: jax.jit(decode_step))
     if params is None:
         raise ValueError("mesh decode steps derive in_specs from params")
 
     def build():
         specs = substrate.serve_param_specs(params)
         sm = jax.shard_map(
-            lambda p, c, t, i: T.decode_step(p, c, t, i, cfg),
+            decode_step,
             mesh=mesh,
             in_specs=(specs, P(), P(), P()),
             out_specs=(P(), P()),
@@ -178,14 +178,12 @@ def prefill_chunk_fn(cfg, mesh: Optional[Mesh] = None, params=None):
     from repro.models import transformer as T
 
     if mesh is None:
+        def prefill_chunk(p, t, c, pos0, nv, max_len, prefix=0):
+            return T.prefill_chunk(p, t, c, pos0, nv, cfg, max_len, prefix)
+
         return _registry_get(
             "prefill_chunk", cfg,
-            lambda: jax.jit(
-                lambda p, t, c, pos0, nv, max_len, prefix=0: T.prefill_chunk(
-                    p, t, c, pos0, nv, cfg, max_len, prefix
-                ),
-                static_argnums=(5, 6),
-            ),
+            lambda: jax.jit(prefill_chunk, static_argnums=(5, 6)),
         )
     if params is None:
         raise ValueError("mesh chunk steps derive in_specs from params")
@@ -193,7 +191,7 @@ def prefill_chunk_fn(cfg, mesh: Optional[Mesh] = None, params=None):
     def build():
         specs = substrate.serve_param_specs(params)
 
-        def fn(p, t, c, pos0, nv, max_len, prefix=0):
+        def prefill_chunk(p, t, c, pos0, nv, max_len, prefix=0):
             sm = jax.shard_map(
                 lambda p, t, c, pos0, nv: T.prefill_chunk(
                     p, t, c, pos0, nv, cfg, max_len, prefix
@@ -205,7 +203,7 @@ def prefill_chunk_fn(cfg, mesh: Optional[Mesh] = None, params=None):
             )
             return sm(p, t, c, pos0, nv)
 
-        return jax.jit(fn, static_argnums=(5, 6))
+        return jax.jit(prefill_chunk, static_argnums=(5, 6))
 
     return _registry_get("prefill_chunk", cfg, build, mesh=mesh)
 
