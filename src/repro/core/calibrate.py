@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from repro.core import rram
 from repro.core.rram import RramConfig
 from repro.optim.adam import AdamW, adamw_init, adamw_update
+from repro.substrate.backends import active_backend_key
 
 Pytree = Any
 
@@ -652,11 +653,51 @@ def calib_step_program(step):
     return calib_step
 
 
-def jit_calib_step(step, *, wrap=None):
+# ---------------------------------------------------------------------------
+# compiled-program registry (mirrors deploy/serving.py and fleet/fleet.py):
+# ONE jitted calibration step and ONE jitted teacher forward per (kind,
+# cfg, opt, trace backend), so repeated ``Deployment.calibrate`` calls
+# reuse their programs instead of tracing, lowering and loading them
+# again. The backend is read at trace time, so the key takes its identity
+# WITH its options (``active_backend_key``). Batch shapes (10 x 32
+# against 320 x 32) are handled by jax.jit's argument cache on the SAME
+# callable.
+# ---------------------------------------------------------------------------
+
+_CALIB_PROGRAMS: Dict[Tuple, Any] = {}
+
+
+def _registry_get(kind: str, cfg, opt, build):
+    key = (kind, cfg, opt, active_backend_key())
+    fn = _CALIB_PROGRAMS.get(key)
+    if fn is None:
+        fn = _CALIB_PROGRAMS[key] = build()
+    return fn
+
+
+def calib_compile_count(cfg) -> int:
+    """Total compiled-computation count across this cfg's registered
+    calibration programs (steps and teacher forwards, any opt, any
+    backend). Flat across repeated same-shape ``Deployment.calibrate``
+    calls; a new batch shape adds one compile to each program it runs."""
+    return sum(fn._cache_size() for key, fn in _CALIB_PROGRAMS.items()
+               if key[1] == cfg)
+
+
+def jit_calib_step(step, *, wrap=None, key=None):
     """``jax.jit`` of ``calib_step_program(step)`` behind the
     ``step(state, *args)`` signature. ``wrap`` transforms the program
     before it is jitted (the fleet vmaps it over chips or shard_maps it
-    over a mesh)."""
+    over a mesh).
+
+    ``key`` is the ``(kind, cfg, opt)`` that ``step`` was made from
+    (``"cached"``: ``make_cached_calib_step``, ``"fused"``:
+    ``make_calib_step``). With it the jitted step is kept in the
+    calibration registry under that key and the active backend: the first
+    request builds it from ``step``, later ones return the same callable
+    and leave theirs unused."""
+    if key is not None:
+        return _registry_get(*key, lambda: jit_calib_step(step, wrap=wrap))
     program = calib_step_program(step)
     jitted = jax.jit(program if wrap is None else wrap(program))
 
@@ -670,6 +711,20 @@ def jit_calib_step(step, *, wrap=None):
 
     run._cache_size = jitted._cache_size   # compile count, as on a jit
     return run
+
+
+def teacher_features_program(cfg):
+    """``teacher_features`` for ``cfg`` as one jitted program
+    ``(teacher_base, batch) -> feats``, kept in the calibration registry
+    under the active backend: the teacher forward is compiled once, not
+    an eager embed plus a freshly lowered scan on every call. The
+    features themselves are computed by every call of the program."""
+
+    def teacher_forward(teacher_base, batch):  # jit_teacher_forward
+        return teacher_features(teacher_base, batch, cfg)
+
+    return _registry_get("teacher", cfg, None,
+                         lambda: jax.jit(teacher_forward))
 
 
 def make_calib_step(

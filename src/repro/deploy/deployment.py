@@ -52,7 +52,7 @@ from repro.core.calibrate import (
     program_model,
     rram_bytes,
     sram_bytes,
-    teacher_features,
+    teacher_features_program,
 )
 from repro.data.pipeline import DataConfig, global_batch_at_step
 from repro.deploy import serving
@@ -347,7 +347,10 @@ class Deployment:
         (enc-dec and VLM configs cache encoder features and the vision
         prefix alongside the decoder chain); codes-resident bases
         execute through the differentiable ``dequant`` backend — the
-        codes stay frozen either way.
+        codes stay frozen either way. The compiled programs (teacher
+        forward and step) are built once per ``(cfg, opt, backend)`` and
+        reused across calls (``core.calibrate``'s registry); the teacher
+        features and every step are computed afresh in each call.
 
         Registry threading (``repro.registry``): with ``registry=`` set,
         the run is persisted as a versioned artifact keyed by this
@@ -381,11 +384,14 @@ class Deployment:
                               else bool(cached_teacher))
                 state = self.calib_state()
                 if use_cached:
-                    feats = teacher_features(self.teacher_base, batch, cfg)
-                    step_fn = jit_calib_step(make_cached_calib_step(cfg, opt))
+                    feats = teacher_features_program(cfg)(
+                        self.teacher_base, batch)
+                    step_fn = jit_calib_step(make_cached_calib_step(cfg, opt),
+                                             key=("cached", cfg, opt))
                     run = lambda s: step_fn(s, feats, batch)
                 else:
-                    step_fn = jit_calib_step(make_calib_step(cfg, opt))
+                    step_fn = jit_calib_step(make_calib_step(cfg, opt),
+                                             key=("fused", cfg, opt))
                     run = lambda s: step_fn(s, batch)
                 if steps > 0:
                     state, metrics = run(state)

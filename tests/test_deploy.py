@@ -6,12 +6,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import deploy
+from repro import deploy, substrate
 from repro.configs import get_arch
 from repro.core import calibrate as C
 from repro.core import rram
 from repro.deploy import Deployment
 from repro.launch import serve, train
+from repro.optim.adam import AdamW
 
 
 def _cfg():
@@ -294,6 +295,122 @@ def test_calibration_report_fields():
     assert report.adapter_params > 0 and report.base_params > 0
     assert report.backend == "dequant"
     assert "sram_bytes" in report.summary()
+
+
+# -- compiled programs reused across calibrate calls ------------------------
+#
+# Each test takes an lr no other test uses, so the registry entries it
+# reads are its own.
+
+
+def _calib_entry(kind, cfg, opt, backend=("dequant", ())):
+    return C._CALIB_PROGRAMS[(kind, cfg, opt, backend)]
+
+
+def test_calibrate_builds_its_programs_once():
+    """Repeated same-shape calls compile nothing new; a new batch shape
+    adds exactly one compile to the step and one to the teacher forward,
+    on the same entries."""
+    cfg = _cfg()
+    opt = AdamW(lr=8e-4)
+    dep = Deployment.program(cfg, 0, backend="codes").advance(24.0)
+    batch = _batch(cfg, b=2, s=16)
+    dep.calibrate(batch, steps=2, opt=opt)
+    first = C.calib_compile_count(cfg)
+    step = _calib_entry("cached", cfg, opt)
+    teacher = _calib_entry("teacher", cfg, None)
+    assert (step._cache_size(), teacher._cache_size()) == (1, 1)
+
+    # an equal AdamW made anew hits the same entry
+    dep.calibrate(batch, steps=2, opt=AdamW(lr=8e-4))
+    dep.calibrate(batch, steps=3, opt=opt)
+    assert C.calib_compile_count(cfg) == first
+
+    dep.calibrate(_batch(cfg, b=3, s=16), steps=2, opt=opt)
+    assert _calib_entry("cached", cfg, opt) is step
+    assert (step._cache_size(), teacher._cache_size()) == (2, 2)
+    assert C.calib_compile_count(cfg) == first + 2
+
+
+@pytest.mark.parametrize("variant", ["opt", "fused"])
+def test_calibrate_registry_separates_opt_and_kind(variant):
+    """A different optimizer, or the fused (uncached-teacher) step, is a
+    different program and builds its own entry."""
+    cfg = _cfg()
+    opt = AdamW(lr=9e-4)
+    dep = Deployment.program(cfg, 0)
+    batch = _batch(cfg, b=2, s=16)
+    dep.calibrate(batch, steps=1, opt=opt)
+    before = set(C._CALIB_PROGRAMS)
+    if variant == "opt":
+        other = AdamW(lr=9e-4, weight_decay=0.01)
+        dep.calibrate(batch, steps=1, opt=other)
+        expected = ("cached", cfg, other)
+    else:
+        dep.calibrate(batch, steps=1, opt=opt, cached_teacher=False)
+        expected = ("fused", cfg, opt)
+    added = set(C._CALIB_PROGRAMS) - before
+    assert [k[:3] for k in added] == [expected]
+    assert C._CALIB_PROGRAMS[added.pop()]._cache_size() == 1
+
+
+def test_registry_calibrate_matches_per_call_jit():
+    """Two successive calls through the registry give, bitwise, the
+    losses, adapters and optimizer state of the per-call path: a fresh
+    jit of the step and eager teacher features in every call."""
+    cfg = _cfg()
+    opt = AdamW(lr=1.5e-3)
+    batch = _batch(cfg, b=2, s=16, seed=3)
+    dep = Deployment.program(cfg, 0, backend="codes").advance(24.0)
+    losses = [dep.calibrate(batch, steps=3, opt=opt).losses
+              for _ in range(2)]
+
+    ref = Deployment.program(cfg, 0, backend="codes").advance(24.0)
+    ref_losses = []
+    with substrate.use_backend("dequant"):
+        for _ in range(2):
+            state = ref.calib_state()
+            feats = C.teacher_features(ref.teacher_base, batch, cfg)
+            step_fn = C.jit_calib_step(C.make_cached_calib_step(cfg, opt))
+            call = []
+            for _ in range(3):
+                state, metrics = step_fn(state, feats, batch)
+                call.append(float(metrics["loss"]))
+            ref.adopt(state)
+            ref_losses.append(call)
+
+    assert losses == ref_losses
+    _assert_trees_equal(dep.adapters, ref.adapters)
+    _assert_trees_equal(dep.opt_state, ref.opt_state)
+    assert dep.step == ref.step == 6
+
+
+def test_calibrate_registry_keys_on_backend_options():
+    """A dequant deployment calibrated under an ambient backend scope
+    with options, then without it, uses two registry entries: a step
+    traced under one backend is never reused under another."""
+    cfg = _cfg()
+    opt = AdamW(lr=6e-4)
+    batch = _batch(cfg, b=2, s=16)
+    dep = Deployment.program(cfg, 0)
+
+    def backends():
+        return {k[3] for k in C._CALIB_PROGRAMS
+                if k[:3] == ("cached", cfg, opt)}
+
+    with substrate.use_backend("codes", accum="int8"):
+        scoped = substrate.active_backend_key()
+        r1 = dep.calibrate(batch, steps=2, opt=opt)
+    assert backends() == {scoped}
+    dep.reset_adapters()
+    r2 = dep.calibrate(batch, steps=2, opt=opt)
+    ambient = substrate.active_backend_key()
+    assert scoped != ambient
+    assert backends() == {scoped, ambient}
+    for b in (scoped, ambient):
+        assert _calib_entry("cached", cfg, opt, b)._cache_size() == 1
+    # a float base never reaches the backend: both entries agree
+    assert r1.losses == r2.losses
 
 
 # -- serving fixes -----------------------------------------------------------
